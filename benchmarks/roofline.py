@@ -10,6 +10,9 @@ from __future__ import annotations
 import json
 import os
 
+from repro.launch.analysis import peaks
+from repro.launch.mesh import TARGET_DEVICE_KIND
+
 DEFAULT_PATH = os.path.join(os.path.dirname(__file__), "..", "results", "dryrun.jsonl")
 
 
@@ -43,8 +46,10 @@ def run(path=DEFAULT_PATH, mesh="16x16", quiet=False):
             "temp_gb": r["mem_temp_bytes"] / 2**30,
         })
     if not quiet:
-        print(f"\n=== Roofline (per device, mesh {mesh}; v5e: 197 TF/s bf16, "
-              f"819 GB/s HBM, 50 GB/s ICI) ===")
+        pk = peaks(TARGET_DEVICE_KIND)
+        print(f"\n=== Roofline (per device, mesh {mesh}; {TARGET_DEVICE_KIND}: "
+              f"{pk['flops'] / 1e12:g} TF/s bf16, {pk['hbm_bw'] / 1e9:g} GB/s HBM, "
+              f"{pk['ici_bw'] / 1e9:g} GB/s ICI) ===")
         print(f"{'arch':22s} {'shape':12s} {'compute_s':>10s} {'memory_s':>9s} "
               f"{'coll_s':>9s} {'bottleneck':>12s} {'MF/HLO':>7s} {'MFUbound':>8s} {'tempGB':>7s}")
         for r in rows:

@@ -243,6 +243,8 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if "--ci" in sys.argv:
         run_ci()
     else:

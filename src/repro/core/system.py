@@ -63,8 +63,12 @@ def build_system(*, relay_enabled: bool = True, encrypt: bool = True,
                  spec_k: int = 4, replicas: int = 1,
                  fleet_overrides: dict | None = None,
                  kv_dtype: str = "fp32",
-                 quantize_mlp: bool = False) -> StreamSystem:
-    """Everything wired, smoke-scale models (CPU-friendly).
+                 quantize_mlp: bool = False, seed: int = 0) -> StreamSystem:
+    """Everything wired, smoke-scale models (CPU-friendly) unless the
+    overrides say otherwise: ``hpc_overrides``/``local_overrides``
+    replace any config field, so passing every field of a published
+    config (``dataclasses.asdict(get_config(arch, ...))``) builds that
+    tier at its published widths. Random weights come from ``seed``.
 
     ``scheduler_slots`` sizes each tier engine's session broker (the
     shared continuous-batching decode batch concurrent sessions
@@ -95,7 +99,7 @@ def build_system(*, relay_enabled: bool = True, encrypt: bool = True,
     tiers with W4A16 AWQ-quantized MLP + attention-output weights (the
     paper's Qwen-72B-AWQ HPC tier); fleet replicas share replica-0's
     quantized params."""
-    rng = jax.random.PRNGKey(0)
+    rng = jax.random.PRNGKey(seed)
 
     # --- engines (the per-tier model servers) ---
     # vocab >= 259 so the byte tokenizer can round-trip real text

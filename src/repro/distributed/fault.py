@@ -33,6 +33,5 @@ def elastic_restore(ckpt: CheckpointManager, model, mesh, *, step=None, rules=No
     shardings = shardings_for_mesh(model, mesh, rules)
     tree, aux, step = ckpt.restore(step, {"params": like}, shardings=None)
     params = tree["params"]
-    with mesh:
-        params = jax.tree.map(lambda x, s: jax.device_put(x, s), params, shardings)
+    params = jax.tree.map(lambda x, s: jax.device_put(x, s), params, shardings)
     return params, aux, step

@@ -74,79 +74,10 @@ def current_rules() -> AxisRules | None:
     return _STATE.rules
 
 
-def get_abstract_mesh():
-    """The abstract mesh active for the current trace, or None.
-
-    ``get_abstract_mesh`` has moved between JAX releases (public
-    ``jax.sharding`` attribute in some, ``jax._src.mesh`` only in
-    others, absent in the oldest). Resolve it wherever this JAX exposes
-    it; callers fall back to the physical mesh context when it yields
-    nothing."""
-    fn = getattr(jax.sharding, "get_abstract_mesh", None)
-    if fn is None:
-        try:
-            from jax._src import mesh as _mesh_impl
-            fn = getattr(_mesh_impl, "get_abstract_mesh", None)
-        except ImportError:
-            fn = None
-    if fn is None:
-        return None
-    try:
-        return fn()
-    except Exception:
-        return None
-
-
-def make_abstract_mesh(axis_sizes: Sequence[int],
-                       axis_names: Sequence[str]) -> AbstractMesh:
-    """Build an AbstractMesh across JAX signature drift.
-
-    Current JAX takes a single ``((name, size), ...)`` shape tuple;
-    older/newer releases take ``(axis_sizes, axis_names)`` positionally.
-    Both call sites (tests, launch analysis) share this helper instead of
-    pinning one signature."""
-    try:
-        return AbstractMesh(tuple(zip(axis_names, axis_sizes)))
-    except TypeError:
-        return AbstractMesh(tuple(axis_sizes), tuple(axis_names))
-
-
-def shard_map_compat(f, *, mesh, in_specs, out_specs):
-    """shard_map across API drift, with replication checking off (the MoE
-    dispatch psums partial results itself): newer releases expose
-    ``jax.shard_map(..., check_vma=...)``, older ones
-    ``jax.experimental.shard_map.shard_map(..., check_rep=...)``."""
-    fn = getattr(jax, "shard_map", None)
-    if fn is not None:
-        try:
-            return fn(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_vma=False)
-        except TypeError:
-            pass
-    from jax.experimental.shard_map import shard_map as _shard_map
-    try:
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=False)
-    except TypeError:
-        return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-
-
-def _active_mesh() -> Mesh | None:
-    mesh = get_abstract_mesh()
-    if mesh is not None and not mesh.empty:
-        return mesh
-    # fall back to the physical mesh context if set
-    try:
-        env_mesh = jax.interpreters.pxla.thread_resources.env.physical_mesh
-        if env_mesh is not None and not env_mesh.empty:
-            return env_mesh
-    except Exception:
-        pass
-    return None
-
-
-def _mesh_axis_sizes(mesh) -> Mapping[str, int]:
-    return dict(zip(mesh.axis_names, mesh.shape.values() if hasattr(mesh.shape, "values") else mesh.devices.shape))
+def _active_mesh() -> AbstractMesh | None:
+    """The mesh set by ``jax.set_mesh`` for the current trace, or None."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return None if mesh.empty else mesh
 
 
 def _resolve_one(name: str | None, dim: int | None, mesh, rules: AxisRules):
@@ -220,8 +151,6 @@ def shard_as(x, *logical: str | None):
     spec = logical_to_pspec(logical, shape=x.shape, mesh=mesh)
     if all(s is None for s in spec):
         return x
-    if isinstance(mesh, Mesh):
-        return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
     return jax.lax.with_sharding_constraint(x, spec)
 
 

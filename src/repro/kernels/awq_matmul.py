@@ -35,11 +35,12 @@ def _awq_kernel(x_ref, qw_ref, s_ref, z_ref, o_ref, acc_ref, *, bits):
     qw = qw_ref[...]                                   # (bk/pack, bn) int32
     pack = 32 // bits
     mask = (1 << bits) - 1
-    u = qw.astype(jnp.uint32)
-    parts = [((u >> (bits * i)) & mask).astype(jnp.float32) for i in range(pack)]
+    # logical shifts on int32: Mosaic has no uint32 -> float32 cast
+    parts = [(jax.lax.shift_right_logical(qw, bits * i) & mask).astype(jnp.float32)
+             for i in range(pack)]
     w_int = jnp.stack(parts, axis=1).reshape(qw.shape[0] * pack, qw.shape[1])
-    s = s_ref[...].astype(jnp.float32)                 # (1, bn)
-    z = z_ref[...].astype(jnp.float32)
+    s = s_ref[0].astype(jnp.float32)                   # (1, bn)
+    z = z_ref[0].astype(jnp.float32)
     w = (w_int - z) * s                                # (bk, bn)
     acc_ref[...] += jax.lax.dot_general(
         x, w, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
@@ -50,13 +51,13 @@ def _awq_kernel(x_ref, qw_ref, s_ref, z_ref, o_ref, acc_ref, *, bits):
 
 
 def awq_matmul(x, qw, scales, zeros, *, bits=4, group_size=128,
-               interpret=False, block_m=128, block_n=128, block_k=None):
-    """x (M, K) @ dequant(qw (K/pack, N)) -> (M, N)."""
+               interpret=False, block_m=128, block_n=128):
+    """x (M, K) @ dequant(qw (K/pack, N)) -> (M, N). The K tile is one
+    quantization group, so each grid step reads one scale/zero row."""
     M, K = x.shape
     pack = 32 // bits
     N = qw.shape[1]
-    bk = group_size if block_k is None else block_k
-    assert bk == group_size, "K tile must equal the quantization group"
+    bk = group_size
     assert K % bk == 0
     bm = min(block_m, M)
     bn = min(block_n, N)
@@ -64,6 +65,12 @@ def awq_matmul(x, qw, scales, zeros, *, bits=4, group_size=128,
     if padm:
         x = jnp.pad(x, ((0, padm), (0, 0)))
     assert N % bn == 0, (N, bn)
+    # one scale/zero row per K tile: viewed as (K/g, 1, N), that row is a
+    # (1, bn) block whose second-minor dim equals the array's, as the
+    # TPU tiling rule requires of a block that is not a multiple of 8
+    groups = scales.shape[0]
+    scales = scales.reshape(groups, 1, N)
+    zeros = zeros.reshape(groups, 1, N)
 
     out = pl.pallas_call(
         functools.partial(_awq_kernel, bits=bits),
@@ -71,8 +78,8 @@ def awq_matmul(x, qw, scales, zeros, *, bits=4, group_size=128,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda im, jn, ik: (im, ik)),
             pl.BlockSpec((bk // pack, bn), lambda im, jn, ik: (ik, jn)),
-            pl.BlockSpec((1, bn), lambda im, jn, ik: (ik, jn)),
-            pl.BlockSpec((1, bn), lambda im, jn, ik: (ik, jn)),
+            pl.BlockSpec((1, 1, bn), lambda im, jn, ik: (ik, 0, jn)),
+            pl.BlockSpec((1, 1, bn), lambda im, jn, ik: (ik, 0, jn)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda im, jn, ik: (im, jn)),
         out_shape=jax.ShapeDtypeStruct((M + padm, N), x.dtype),

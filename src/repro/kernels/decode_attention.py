@@ -22,7 +22,7 @@ NEG_INF = -1e30
 
 
 def _dec_kernel(kvlen_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
-                scale, block_k, soft_cap):
+                scale, block_k, soft_cap, n_kv_heads):
     ik = pl.program_id(1)
     nk = pl.num_programs(1)
 
@@ -32,7 +32,7 @@ def _dec_kernel(kvlen_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    kv_len = kvlen_ref[0]
+    kv_len = kvlen_ref[pl.program_id(0) // n_kv_heads]
     k_start = ik * block_k
 
     @pl.when(k_start < kv_len)
@@ -73,29 +73,33 @@ def decode_attention(q, k, v, *, kv_len, scale=None, logit_soft_cap=0.0,
     assert S % bk == 0
 
     kv_len = jnp.broadcast_to(jnp.asarray(kv_len, jnp.int32).reshape(-1), (B,))
-    kv_rep = jnp.repeat(kv_len, Hkv)                      # (B*Hkv,)
     qf = q.reshape(B, Hkv, G, D).reshape(B * Hkv, G, D)
     kf = k.reshape(B * Hkv, S, D)
     vf = v.reshape(B * Hkv, S, D)
 
     kernel = functools.partial(_dec_kernel, scale=scale, block_k=bk,
-                               soft_cap=logit_soft_cap)
-    out = pl.pallas_call(
-        kernel,
+                               soft_cap=logit_soft_cap, n_kv_heads=Hkv)
+    # per-slot lengths are scalar-prefetched into SMEM (a rank-1 VMEM
+    # block of one int32 is below the TPU's 128-lane tiling)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(B * Hkv, S // bk),
         in_specs=[
-            pl.BlockSpec((1,), lambda bh, ik: (bh,)),
-            pl.BlockSpec((1, G, D), lambda bh, ik: (bh, 0, 0)),
-            pl.BlockSpec((1, bk, D), lambda bh, ik: (bh, ik, 0)),
-            pl.BlockSpec((1, bk, D), lambda bh, ik: (bh, ik, 0)),
+            pl.BlockSpec((1, G, D), lambda bh, ik, kl: (bh, 0, 0)),
+            pl.BlockSpec((1, bk, D), lambda bh, ik, kl: (bh, ik, 0)),
+            pl.BlockSpec((1, bk, D), lambda bh, ik, kl: (bh, ik, 0)),
         ],
-        out_specs=pl.BlockSpec((1, G, D), lambda bh, ik: (bh, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B * Hkv, G, D), q.dtype),
+        out_specs=pl.BlockSpec((1, G, D), lambda bh, ik, kl: (bh, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((G, D), jnp.float32),
             pltpu.VMEM((G, 1), jnp.float32),
             pltpu.VMEM((G, 1), jnp.float32),
         ],
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B * Hkv, G, D), q.dtype),
         interpret=interpret,
-    )(kv_rep, qf, kf, vf)
+    )(kv_len, qf, kf, vf)
     return out.reshape(B, Hq, D)[:, :, None, :]

@@ -154,10 +154,9 @@ def ssd_step(x, dt, A, B, C, D, h):
 # -- quantized matmul ------------------------------------------------------
 
 def awq_matmul(x, qw, scales, zeros, *, bits=4, group_size=128,
-               impl="ref", interpret=False, block_m=128, block_n=128, block_k=256):
+               impl="ref", interpret=False, block_m=128, block_n=128):
     if _resolve(impl) == "ref":
         return _ref.awq_matmul(x, qw, scales, zeros, bits=bits, group_size=group_size)
     from repro.kernels import awq_matmul as _k
     return _k.awq_matmul(x, qw, scales, zeros, bits=bits, group_size=group_size,
-                         interpret=interpret, block_m=block_m, block_n=block_n,
-                         block_k=block_k)
+                         interpret=interpret, block_m=block_m, block_n=block_n)

@@ -55,13 +55,13 @@ def _paged_kernel(bt_ref, kvlen_ref, posoff_ref, q_ref, k_ref, v_ref, o_ref,
         q = q_ref[0].astype(jnp.float32)            # (G, D)
         k = k_ref[0, 0].astype(jnp.float32)         # (page, D)
         v = v_ref[0, 0].astype(jnp.float32)
-        if ks_ref is not None:
-            # quantized pool page: dequant in-register with the page's
-            # per-position scales — the pool is never widened in HBM
-            k = k * ks_ref[0, 0][:, None]
-            v = v * vs_ref[0, 0][:, None]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
+        if ks_ref is not None:
+            # quantized pool page: a per-position scale of k row j is a
+            # scale of score column j, so dequant rides the (1, page)
+            # lane row of the sidecar — the pool is never widened in HBM
+            s = s * ks_ref[0, 0]
         if soft_cap > 0.0:
             s = soft_cap * jnp.tanh(s / soft_cap)
         kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
@@ -72,8 +72,10 @@ def _paged_kernel(bt_ref, kvlen_ref, posoff_ref, q_ref, k_ref, v_ref, o_ref,
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m_prev - m_new)
         l_ref[...] = l_ref[...] * alpha + p.sum(axis=1, keepdims=True)
+        # likewise v's per-position scales fold into p's columns
+        pv = p if vs_ref is None else p * vs_ref[0, 0]
         acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+            pv, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
         m_ref[...] = m_new
 
     @pl.when(ip == np_ - 1)
@@ -120,7 +122,7 @@ def paged_attention(q, k_pages, v_pages, *, block_tables, kv_len, scale=None,
 
     def scale_map(bh, ip, bt_ref, kvlen_ref, posoff_ref):
         pid = bt_ref[(bh // Hkv) * n_pages + ip]
-        return (pid, bh % Hkv, 0)
+        return (pid, bh % Hkv, 0, 0)
 
     quant = k_scales is not None
     in_specs = [
@@ -131,12 +133,13 @@ def paged_attention(q, k_pages, v_pages, *, block_tables, kv_len, scale=None,
     operands = [qf, k_pages, v_pages]
     if quant:
         # the scale sidecar rides the same scalar-prefetched block-table
-        # steering as the pages themselves: one (1, 1, page) block per
-        # grid step, landing next to its page for the in-kernel dequant
-        in_specs += [pl.BlockSpec((1, 1, page), scale_map),
-                     pl.BlockSpec((1, 1, page), scale_map)]
-        operands += [k_scales.astype(jnp.float32),
-                     v_scales.astype(jnp.float32)]
+        # steering as the pages themselves. Viewed as (P, Hkv, 1, page),
+        # each grid step's block is one (1, page) row whose dims equal
+        # the array's last two, as the TPU tiling rule requires
+        in_specs += [pl.BlockSpec((1, 1, 1, page), scale_map),
+                     pl.BlockSpec((1, 1, 1, page), scale_map)]
+        operands += [k_scales.astype(jnp.float32).reshape(P, Hkv, 1, page),
+                     v_scales.astype(jnp.float32).reshape(P, Hkv, 1, page)]
     kernel = functools.partial(
         _paged_kernel_quant if quant else _paged_kernel,
         scale=scale, page=page, n_kv_heads=Hkv, soft_cap=logit_soft_cap)

@@ -355,22 +355,37 @@ def analytic_costs(cfg, cell) -> dict:
 # roofline terms
 # ---------------------------------------------------------------------------
 
-PEAK_FLOPS = 197e12       # bf16 / chip (TPU v5e)
-HBM_BW = 819e9            # bytes/s / chip
-ICI_BW = 50e9             # bytes/s / link
+# Published per-chip peaks, keyed by ``jax.Device.device_kind``.
+# Source: Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16,
+# 819 GB/s HBM bandwidth, 1,600 Gbit/s of inter-chip interconnect
+# (taken here as four links of 50 GB/s). A kind missing from the table
+# is an error, never a default.
+PEAKS = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bw": 819e9, "ici_bw": 50e9},
+}
 
 
-def roofline_terms(rec: dict, n_devices: int) -> dict:
-    """Per-device seconds for each roofline term + the bottleneck."""
+def peaks(device_kind: str) -> dict:
+    """The published peaks of one chip of ``device_kind``."""
+    if device_kind not in PEAKS:
+        raise KeyError(f"no published peaks for device kind {device_kind!r}; "
+                       f"known: {sorted(PEAKS)}")
+    return PEAKS[device_kind]
+
+
+def roofline_terms(rec: dict, n_devices: int, device_kind: str) -> dict:
+    """Per-device seconds for each roofline term on ``device_kind`` chips
+    + the bottleneck."""
+    pk = peaks(device_kind)
     flops_dev = rec["hlo_flops_per_device"]
     hbm_dev = rec["hbm_bytes_global"] / n_devices
     coll_dev = rec["collective_bytes_total_per_device"]
-    t_compute = flops_dev / PEAK_FLOPS
-    t_memory = hbm_dev / HBM_BW
-    t_coll = coll_dev / ICI_BW
+    t_compute = flops_dev / pk["flops"]
+    t_memory = hbm_dev / pk["hbm_bw"]
+    t_coll = coll_dev / pk["ici_bw"]
     terms = {"compute_s": t_compute, "memory_s": t_memory, "collective_s": t_coll}
     bottleneck = max(terms, key=terms.get)
     step_s = max(terms.values())
     return {**terms, "bottleneck": bottleneck, "step_time_bound_s": step_s,
             "model_flops_ratio": (rec["model_flops_global"] / n_devices) / max(flops_dev, 1.0),
-            "mfu_bound": (rec["model_flops_global"] / n_devices / PEAK_FLOPS) / max(step_s, 1e-12)}
+            "mfu_bound": (rec["model_flops_global"] / n_devices / pk["flops"]) / max(step_s, 1e-12)}

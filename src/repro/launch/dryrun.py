@@ -33,7 +33,7 @@ import numpy as np
 from repro.configs import SHAPES, get_config, list_archs, shapes_for
 from repro.distributed.sharding import (DEFAULT_RULES, axis_rules, logical_to_pspec,
                                         spec_tree_to_shardings)
-from repro.launch.mesh import make_production_mesh
+from repro.launch.mesh import TARGET_DEVICE_KIND, make_production_mesh
 from repro.models import build_model
 from repro.models.common import count_params, shape_tree, spec_tree
 from repro.training.optim import AdamWConfig
@@ -132,7 +132,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool, verbose: bool = Tru
     extra = _extra_from_specs(specs)
 
     t0 = time.time()
-    with mesh, axis_rules(rules):
+    with jax.set_mesh(mesh), axis_rules(rules):
         p_shard = spec_tree_to_shardings(p_specs, p_shapes, mesh, rules)
 
         if cell.kind == "train":
@@ -249,7 +249,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool, verbose: bool = Tru
         "mem_temp_bytes": _m("temp_size_in_bytes"),
         "ok": True,
     }
-    rec["roofline"] = roofline_terms(rec, n_dev)
+    rec["roofline"] = roofline_terms(rec, n_dev, TARGET_DEVICE_KIND)
     if verbose:
         print(json.dumps(rec))
         sys.stdout.flush()

@@ -12,6 +12,7 @@ import json
 
 from repro.core import build_system
 from repro.core.sse import parse_sse
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
@@ -30,6 +31,7 @@ def main():
                     help="serve W4A16 AWQ-quantized MLP/attn-out weights "
                          "on both tiers")
     args = ap.parse_args()
+    enable_compile_cache()
 
     print("building STREAM system (three tiers + relay + proxy)...")
     sys_ = build_system(hpc_arch=args.arch, dispatch_latency_s=0.05, max_seq=256,

@@ -10,6 +10,7 @@ extract the logical-axis tree for pjit shardings (``spec_tree``).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from dataclasses import dataclass, field
 from typing import Any
@@ -156,33 +157,43 @@ def _fan_in(shape):
     return int(np.prod(shape[:-1])) if len(shape) == 2 else int(np.prod(shape[-2:-1]))
 
 
-def init_params(defs, rng, param_dtype=jnp.float32):
-    """Materialize a ParamDef tree into actual arrays."""
-    leaves, treedef = jax.tree.flatten(defs, is_leaf=_is_def)
+def _init_leaf(d: ParamDef, key, dt):
+    if d.init == "zeros":
+        return jnp.zeros(d.shape, dt)
+    if d.init == "ones":
+        return jnp.ones(d.shape, dt)
+    if d.init == "normal" or d.init == "embed":
+        std = 0.02 * d.scale
+        return (jax.random.normal(key, d.shape, jnp.float32) * std).astype(dt)
+    if d.init == "scaled":  # 1/sqrt(fan_in)
+        fan = d.shape[-2] if len(d.shape) >= 2 else d.shape[0]
+        std = d.scale / math.sqrt(max(fan, 1))
+        return (jax.random.normal(key, d.shape, jnp.float32) * std).astype(dt)
+    if d.init == "ssm_a":   # mamba A_log in [1, 16]
+        u = jax.random.uniform(key, d.shape, jnp.float32, 1.0, 16.0)
+        return jnp.log(u).astype(dt)
+    if d.init == "ssm_dt":  # dt bias ~ softplus-inv of U(1e-3, 1e-1)
+        u = jax.random.uniform(key, d.shape, jnp.float32, 1e-3, 1e-1)
+        return jnp.log(jnp.expm1(u)).astype(dt)
+    raise ValueError(f"unknown init {d.init}")
+
+
+@functools.partial(jax.jit, static_argnums=(0, 2))
+def _init_leaves(leaves: tuple, rng, param_dtype):
     rngs = jax.random.split(rng, len(leaves))
+    return [_init_leaf(d, k, d.dtype or param_dtype)
+            for d, k in zip(leaves, rngs)]
 
-    def one(d: ParamDef, key):
-        dt = d.dtype or param_dtype
-        if d.init == "zeros":
-            return jnp.zeros(d.shape, dt)
-        if d.init == "ones":
-            return jnp.ones(d.shape, dt)
-        if d.init == "normal" or d.init == "embed":
-            std = 0.02 * d.scale
-            return (jax.random.normal(key, d.shape, jnp.float32) * std).astype(dt)
-        if d.init == "scaled":  # 1/sqrt(fan_in)
-            fan = d.shape[-2] if len(d.shape) >= 2 else d.shape[0]
-            std = d.scale / math.sqrt(max(fan, 1))
-            return (jax.random.normal(key, d.shape, jnp.float32) * std).astype(dt)
-        if d.init == "ssm_a":   # mamba A_log in [1, 16]
-            u = jax.random.uniform(key, d.shape, jnp.float32, 1.0, 16.0)
-            return jnp.log(u).astype(dt)
-        if d.init == "ssm_dt":  # dt bias ~ softplus-inv of U(1e-3, 1e-1)
-            u = jax.random.uniform(key, d.shape, jnp.float32, 1e-3, 1e-1)
-            return jnp.log(jnp.expm1(u)).astype(dt)
-        raise ValueError(f"unknown init {d.init}")
 
-    return treedef.unflatten([one(d, k) for d, k in zip(leaves, rngs)])
+def init_params(defs, rng, param_dtype=jnp.float32):
+    """Materialize a ParamDef tree into actual arrays. Every leaf is
+    drawn and cast inside one jitted program, so each float32 draw fuses
+    into its cast and no leaf is ever held on the device wider than its
+    own dtype (a stacked bf16 MLP leaf at published widths would
+    otherwise pass through a float32 copy twice its size)."""
+    leaves, treedef = jax.tree.flatten(defs, is_leaf=_is_def)
+    return treedef.unflatten(
+        _init_leaves(tuple(leaves), rng, jnp.dtype(param_dtype)))
 
 
 def shape_tree(defs, param_dtype=jnp.float32):

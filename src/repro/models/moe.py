@@ -77,8 +77,7 @@ def moe_mlp_shard_map(x, p, cfg: ModelConfig, *, capacity_factor: float):
     what GSPMD does for dense layers, minus the pathological scatter
     resharding (measured: 72s -> see EXPERIMENTS.md)."""
     from jax.sharding import PartitionSpec as P
-    from repro.distributed.sharding import (_active_mesh, logical_to_pspec,
-                                            shard_map_compat)
+    from repro.distributed.sharding import _active_mesh, logical_to_pspec
 
     mesh = _active_mesh()
     B, S, D = x.shape
@@ -144,8 +143,9 @@ def moe_mlp_shard_map(x, p, cfg: ModelConfig, *, capacity_factor: float):
         return logical_to_pspec(logical, shape, mesh)
 
     bspec = spec_of(("batch", None, None), x.shape)
-    out = shard_map_compat(
-        local, mesh=mesh,
+    # replication checking off: ``local`` psums its partial results itself
+    out = jax.shard_map(
+        local, mesh=mesh, check_vma=False,
         in_specs=(bspec,
                   spec_of(("embed", None), p["router"].shape),
                   spec_of(("experts", "embed", "expert_ffn"), p["w1"].shape),

@@ -61,7 +61,8 @@ def test_elastic_restore_onto_new_mesh():
     with tempfile.TemporaryDirectory() as d:
         cm = CheckpointManager(d)
         cm.save(3, {"params": params}, aux={"note": "pre-resize"})
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = jax.make_mesh((1, 1), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         restored, aux, step = elastic_restore(cm, model, mesh)
         assert step == 3 and aux["note"] == "pre-resize"
         for a, b in zip(jax.tree.leaves(restored), jax.tree.leaves(params)):

@@ -23,15 +23,16 @@ cfg = get_smoke_config(arch).replace(
     remat=False, compute_dtype="float32", capacity_factor=4.0,
     eval_capacity_factor=4.0)
 tokens = jax.random.randint(jax.random.PRNGKey(2), (4, 16), 0, cfg.vocab_size)
-mesh = jax.make_mesh((2, 2), ("data", "model"))
+mesh = jax.make_mesh((2, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 model = build_model(cfg)
 params = model.init(jax.random.PRNGKey(1))
-with mesh, axis_rules(DEFAULT_RULES):
+with jax.set_mesh(mesh), axis_rules(DEFAULT_RULES):
     ref = jax.jit(model.forward)(params, tokens)
 cfg2 = cfg.replace(moe_dispatch="shard_map", capacity_factor=8.0,
                    eval_capacity_factor=8.0)
 m2 = build_model(cfg2)
-with mesh, axis_rules(DEFAULT_RULES):
+with jax.set_mesh(mesh), axis_rules(DEFAULT_RULES):
     out = jax.jit(m2.forward)(params, tokens)
 err = float(jnp.abs(ref - out).max())
 assert err < 1e-4, err

@@ -5,15 +5,15 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AbstractMesh, PartitionSpec as P
 
-from repro.distributed.sharding import (DEFAULT_RULES, logical_to_pspec,
-                                        make_abstract_mesh)
+from repro.distributed.sharding import DEFAULT_RULES, logical_to_pspec
 from repro.launch.analysis import analytic_costs, analyze_hlo, roofline_terms
+from repro.launch.mesh import TARGET_DEVICE_KIND
 from repro.configs import SHAPES, get_config
 
-MESH_1POD = make_abstract_mesh((16, 16), ("data", "model"))
-MESH_2POD = make_abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+MESH_1POD = AbstractMesh((16, 16), ("data", "model"))
+MESH_2POD = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def test_basic_resolution():
@@ -115,7 +115,14 @@ def test_roofline_terms_pick_bottleneck():
            "hbm_bytes_global": 819e9 * 256 * 0.5,
            "collective_bytes_total_per_device": 50e9 * 0.1,
            "model_flops_global": 197e12 * 256}
-    t = roofline_terms(rec, 256)
+    t = roofline_terms(rec, 256, TARGET_DEVICE_KIND)
     assert t["bottleneck"] == "compute_s"
     assert t["compute_s"] == pytest.approx(1.0)
     assert t["mfu_bound"] == pytest.approx(1.0)
+
+
+def test_roofline_terms_refuse_unknown_device_kind():
+    rec = {"hlo_flops_per_device": 1.0, "hbm_bytes_global": 1.0,
+           "collective_bytes_total_per_device": 1.0, "model_flops_global": 1.0}
+    with pytest.raises(KeyError, match="cpu"):
+        roofline_terms(rec, 1, "cpu")
