@@ -16,6 +16,8 @@ import base64
 import json
 import time
 
+import jax
+
 from repro.core.crypto import AESGCM, decrypt_envelope, encrypt_envelope
 from repro.core.relay import Relay
 
@@ -65,8 +67,9 @@ class TokenProducer:
         self.n_tokens = 0      # tokens only
 
     def _send(self, payload: dict):
-        self._prod.send(encrypt_envelope(self._aes, payload)
-                        if self._aes else payload)
+        with jax.profiler.TraceAnnotation("stream.relay.encrypt"):
+            self._prod.send(encrypt_envelope(self._aes, payload)
+                            if self._aes else payload)
 
     def push(self, token_id, text: str):
         self._send({"t": "token", "seq": self.seq,
@@ -115,15 +118,17 @@ def consume_tokens(relay: Relay, channel_id: str, secret: str,
             msg = cons.recv(timeout=timeout_s)
             if msg is None:
                 return
-            payload = decrypt_envelope(aes, msg) if aes else msg
-            if payload.get("t") == "error":
-                raise RuntimeError(f"producer error: {payload.get('error')}")
-            if payload.get("t") == "done":
-                return
-            if payload.get("seq") != expect:
-                raise RuntimeError(
-                    f"out-of-order token: got seq={payload.get('seq')}, want {expect}")
-            expect += 1
+            # one span per message, the wait in recv left out
+            with jax.profiler.TraceAnnotation("stream.relay.decrypt"):
+                payload = decrypt_envelope(aes, msg) if aes else msg
+                if payload.get("t") == "error":
+                    raise RuntimeError(f"producer error: {payload.get('error')}")
+                if payload.get("t") == "done":
+                    return
+                if payload.get("seq") != expect:
+                    raise RuntimeError(
+                        f"out-of-order token: got seq={payload.get('seq')}, want {expect}")
+                expect += 1
             yield payload
     finally:
         cons.close()

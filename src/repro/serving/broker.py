@@ -58,6 +58,11 @@ class SessionHandle:
         self.rid = rid
         self.submitted_at = time.perf_counter()
         self.ttft_s: Optional[float] = None
+        # set with the first token, on the submitted_at clock: the pop
+        # from the batcher's queue (the end of the wait in mailbox and
+        # queue) and the first token's emission
+        self.admitted_at: Optional[float] = None
+        self.first_token_at: Optional[float] = None
         self.prefix_hit_tokens = 0   # set with the first token
         self._cancel_fn = cancel_fn
         self._event = threading.Event()
@@ -134,7 +139,9 @@ class SessionBroker:
 
         def tok_cb(tid: int, text: str):
             if handle.ttft_s is None:
-                handle.ttft_s = time.perf_counter() - handle.submitted_at
+                handle.first_token_at = time.perf_counter()
+                handle.admitted_at = req.admitted_at
+                handle.ttft_s = handle.first_token_at - handle.submitted_at
                 handle.prefix_hit_tokens = req.prefix_hit_tokens
                 if on_meta is not None:
                     meta = {"prefix_hit_tokens": req.prefix_hit_tokens}

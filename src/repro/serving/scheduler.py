@@ -138,6 +138,7 @@ class Request:
     cache_salt: str = ""             # prefix-cache tenant key (gateway auth)
     prefix_hit_tokens: int = 0       # prefill tokens served from the cache
     submitted_at: float = field(default_factory=time.perf_counter)
+    admitted_at: Optional[float] = None   # popped from the queue for admission
     output_ids: list = field(default_factory=list)
     done: bool = False
     cancelled: bool = False
@@ -313,7 +314,6 @@ class ContinuousBatcher:
         self._fused = jax.jit(self._make_fused())
         self._first = jax.jit(self._make_first())
         self.transfers = 0           # packed reads; one per decode tick
-        self.adm_transfers = 0       # scalar first-token reads; one per admission
 
         # ---- speculative decoding (propose_k / verify_chunk contract).
         # A tick with drafts runs ONE fused verify step over a (B, W)
@@ -724,13 +724,23 @@ class ContinuousBatcher:
         of prefill chunks (``prefill_chunk`` tokens of pages; ALL of them
         when the batch is idle — pacing only exists to protect in-flight
         decodes). Called at tick start and again after reaping, so a
-        slot freed by cancellation is re-admitted on the same tick."""
-        if self._adm is None:
+        slot freed by cancellation is re-admitted on the same tick. A
+        call that pops or advances an admission is one
+        ``stream.batcher.admit`` span, tagged with the head request."""
+        if self._adm is not None:
+            head, slot = self._adm.req, None
+        else:
             if not self.queue:
                 return
             slot = next((s for s in range(self.B) if self.active[s] is None), None)
             if slot is None:
                 return
+            head = self.queue[0]
+        with jax.profiler.TraceAnnotation("stream.batcher.admit", rid=head.rid):
+            self._admit(slot)
+
+    def _admit(self, slot):
+        if self._adm is None:
             # expire deadlined requests at the pop — don't burn a full
             # prefill + splice (and emit a stale token) for a session
             # whose client already timed out waiting in the queue
@@ -748,6 +758,7 @@ class ContinuousBatcher:
                 break
             if req is None:
                 return
+            req.admitted_at = now
             w = self.window
             if w is None:
                 ids, req.max_new_tokens = clip_prompt(
@@ -891,7 +902,6 @@ class ContinuousBatcher:
                                adm.temp, adm.top_p, adm.seed)
         self._adm = None
         first = int(self.tok[slot, 0])
-        self.adm_transfers += 1
         req.output_ids.append(first)
         stopped = req.emit(first, self.tokenizer.decode_token(first))
         # the emission just woke the session's consumer thread (gateway
@@ -1062,13 +1072,18 @@ class ContinuousBatcher:
         else:
             drafts = jnp.asarray(self._draft_host)
         lens = self._draft_len.copy()
-        self.tok, self.cache, packed = self._verify(
-            self.engine.params, self.tok, drafts, jnp.asarray(lens),
-            self.cache, self._active_m, self._gen, self._maxgen,
-            self._temp, self._topp, self._seed, rng)
-        packed = np.asarray(packed)  # still the tick's one token transfer
+        with jax.profiler.TraceAnnotation("stream.batcher.decode"):
+            self.tok, self.cache, packed = self._verify(
+                self.engine.params, self.tok, drafts, jnp.asarray(lens),
+                self.cache, self._active_m, self._gen, self._maxgen,
+                self._temp, self._topp, self._seed, rng)
+            packed = np.asarray(packed)  # still the tick's one token transfer
         self.transfers += 1
         self.spec_stats.spec_ticks += 1
+        with jax.profiler.TraceAnnotation("stream.batcher.emit"):
+            self._emit_window(packed, lens, W)
+
+    def _emit_window(self, packed, lens, W: int):
         now = time.perf_counter()
         for slot, req in enumerate(self.active):
             if req is None:
@@ -1102,7 +1117,13 @@ class ContinuousBatcher:
     def step(self) -> int:
         """One scheduler tick: admit (one chunk), fused decode, emit, reap,
         re-admit. Returns the number of requests still in flight (active
-        slots plus a mid-prefill admission), so callers may loop on it."""
+        slots plus a mid-prefill admission), so callers may loop on it.
+        The tick is one ``stream.batcher.tick`` span holding its
+        ``admit``, ``decode`` and ``emit`` spans."""
+        with jax.profiler.TraceAnnotation("stream.batcher.tick"):
+            return self._tick()
+
+    def _tick(self) -> int:
         self._freed = False
         self._stall = False
         idle = not any(r is not None for r in self.active)
@@ -1131,12 +1152,23 @@ class ContinuousBatcher:
             return self._in_flight()
         if self.spec:
             self.spec_stats.plain_ticks += 1
-        self.tok, self.cache, packed = self._fused(
-            self.engine.params, self.tok, self.cache,
-            self._active_m, self._gen, self._maxgen,
-            self._temp, self._topp, self._seed, k)
-        packed = np.asarray(packed)  # the tick's one token transfer
+        with jax.profiler.TraceAnnotation("stream.batcher.decode"):
+            self.tok, self.cache, packed = self._fused(
+                self.engine.params, self.tok, self.cache,
+                self._active_m, self._gen, self._maxgen,
+                self._temp, self._topp, self._seed, k)
+            packed = np.asarray(packed)  # the tick's one token transfer
         self.transfers += 1
+        with jax.profiler.TraceAnnotation("stream.batcher.emit"):
+            self._emit(packed)
+        # same-tick reuse of reaped slots — but never advance an already
+        # in-flight admission a second chunk (one chunk per tick)
+        if self._freed and self._adm is None:
+            self._advance_admissions()
+        return self._in_flight()
+
+    def _emit(self, packed):
+        """Deliver one decode tick's tokens and reap finished slots."""
         now = time.perf_counter()
         for slot, req in enumerate(self.active):
             if req is None:
@@ -1155,11 +1187,6 @@ class ContinuousBatcher:
             over = req.deadline_s and (now - req.submitted_at) > req.deadline_s
             if done or over:
                 self._finish(slot, cancelled=bool(over))
-        # same-tick reuse of reaped slots — but never advance an already
-        # in-flight admission a second chunk (one chunk per tick)
-        if self._freed and self._adm is None:
-            self._advance_admissions()
-        return self._in_flight()
 
     def run_until_drained(self, max_steps: int = 10000):
         steps = 0
