@@ -85,14 +85,17 @@ def paged_attention(q, k_pages, v_pages, *, block_tables, kv_len, scale=None,
                     pos_offset=None, k_scales=None, v_scales=None):
     """Paged decode attention: q (B,Hq,1,D) against pooled KV pages
     (P,Hkv,page,D) addressed through per-slot block tables (B,n_pages).
-    The ref path gathers the pages into a contiguous view; the Pallas
-    path DMAs pages inside the kernel via scalar-prefetched tables.
+    The ref path gathers the pages into a contiguous view. The Pallas
+    path runs one program per slot and DMAs the slot's live pages, all
+    kv heads per page, in double-buffered blocks steered by the scalar-
+    prefetched table; its block loop stops at the slot's last live page,
+    so pages past kv_len are neither copied nor computed.
     ``pos_offset`` (scalar or (B,)) is the per-slot count of tokens
     rolled out of the window: the block table maps only surviving
     pages, so the slot-space KV length is kv_len - pos_offset.
     ``k_scales``/``v_scales`` ((P,Hkv,page) float32) mark the pool as
-    quantized: both impls dequantize per page position before the
-    attention math (in-register for the Pallas path)."""
+    quantized: both impls dequantize per page position in the attention
+    math (in-register, per block, for the Pallas path)."""
     if _resolve(impl) == "ref":
         return _ref.paged_attention(q, k_pages, v_pages,
                                     block_tables=block_tables, kv_len=kv_len,

@@ -167,8 +167,9 @@ def paged_attention(q, k_pages, v_pages, *, block_tables, kv_len, scale=None,
                     k_scales=None, v_scales=None):
     """Paged decode attention, pure-jnp oracle: gather the block-table
     row into a contiguous (B, Hkv, S, D) view, then run the standard
-    decode attention. The Pallas kernel performs the same gather
-    page-by-page inside the kernel via scalar-prefetched block tables.
+    decode attention. The Pallas kernel performs the same gather inside
+    the kernel, a block of live pages at a time, via the scalar-
+    prefetched block table.
 
     q: (B, Hq, 1, D); k_pages, v_pages: (P, Hkv, page, D);
     block_tables: (B, n_pages); kv_len: scalar or (B,).

@@ -314,6 +314,10 @@ class ContinuousBatcher:
         self._fused = jax.jit(self._make_fused())
         self._first = jax.jit(self._make_first())
         self.transfers = 0           # packed reads; one per decode tick
+        # live KV pages the paged decode kernel walks, summed over plain
+        # decode ticks: the numerator of the share of the table walked,
+        # against transfers * B * n_pages (host state only, no read)
+        self.kv_pages_walked = 0
 
         # ---- speculative decoding (propose_k / verify_chunk contract).
         # A tick with drafts runs ONE fused verify step over a (B, W)
@@ -1152,6 +1156,9 @@ class ContinuousBatcher:
             return self._in_flight()
         if self.spec:
             self.spec_stats.plain_ticks += 1
+        if self.paged:
+            live = (self._pos + 1 - self._poff)[self._active_m]
+            self.kv_pages_walked += int((-(-live // self.page)).sum())
         with jax.profiler.TraceAnnotation("stream.batcher.decode"):
             self.tok, self.cache, packed = self._fused(
                 self.engine.params, self.tok, self.cache,

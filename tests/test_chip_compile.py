@@ -49,18 +49,22 @@ def _compile(fn, sharding, *shapes):
     assert "tpu_custom_call" in text
 
 
+@pytest.mark.parametrize("b,pool", [(B, POOL), (32, 2049)],
+                         ids=["b8-pool512", "b32-pool2049"])
 @pytest.mark.parametrize("kv_dtype", ["bfloat16", "int8", "float8_e4m3fn"])
-def test_paged_attention_compiles(one_chip, kv_dtype):
+def test_paged_attention_compiles(one_chip, kv_dtype, b, pool):
+    """Today's small shape and the hpc-api cell's: 32 slots over a pool
+    of 2,048 pages plus the trash page."""
     kv = jnp.dtype(kv_dtype)
-    shapes = [((B, HQ, 1, D), jnp.bfloat16), ((POOL, HKV, PAGE, D), kv),
-              ((POOL, HKV, PAGE, D), kv), ((B, N_PAGES), jnp.int32),
-              ((B,), jnp.int32), ((B,), jnp.int32)]
+    shapes = [((b, HQ, 1, D), jnp.bfloat16), ((pool, HKV, PAGE, D), kv),
+              ((pool, HKV, PAGE, D), kv), ((b, N_PAGES), jnp.int32),
+              ((b,), jnp.int32), ((b,), jnp.int32)]
     if kv == jnp.bfloat16:
         def fn(q, k, v, bt, kv_len, poff):
             return paged_attention.paged_attention(
                 q, k, v, block_tables=bt, kv_len=kv_len, pos_offset=poff)
     else:
-        shapes += [((POOL, HKV, PAGE), jnp.float32)] * 2
+        shapes += [((pool, HKV, PAGE), jnp.float32)] * 2
 
         def fn(q, k, v, bt, kv_len, poff, ks, vs):
             return paged_attention.paged_attention(

@@ -11,6 +11,7 @@ tables at once.
 
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.kernels import ref
 from repro.kernels.paged_attention import paged_attention
@@ -288,3 +289,125 @@ def test_pos_offset_zero_is_bitwise_default():
                            pos_offset=jnp.zeros((3,), jnp.int32),
                            interpret=True)
     np.testing.assert_array_equal(np.asarray(base), np.asarray(zero))
+
+
+# ------------------------------------------- block stepping (live pages)
+# Shapes at which the derived pages-per-block is small against a 64-page
+# table: one page of all 8 kv heads is 32 KB in bf16 (8 pages a block)
+# and 16 KB in int8 (16 pages a block), so every case below spans
+# several blocks and ends inside, on or just past a block boundary.
+_SB = dict(Hq=4, Hkv=2, D=128, page=64)
+
+
+def _block_pools(rng, pool, *, B, n_pages):
+    """Distinct non-trash pages per slot over a pool of B * n_pages + 1;
+    returns q, pools, table, the scale kwargs and pages_per_block."""
+    from repro.kernels.paged_attention import pages_per_block
+
+    q, kp, vp, bt = _setup(rng, B=B, n_pages=n_pages, P=B * n_pages + 1,
+                           **_SB)
+    kw = {}
+    if pool == "int8":
+        kp, ks, vp, vs = _quantize_pools(kp, vp, jnp.int8)
+        kw = dict(k_scales=ks, v_scales=vs)
+    else:
+        kp, vp = kp.astype(jnp.bfloat16), vp.astype(jnp.bfloat16)
+    page_bytes = _SB["Hkv"] * _SB["page"] * _SB["D"] * kp.dtype.itemsize
+    return q, kp, vp, bt, kw, pages_per_block(page_bytes, n_pages)
+
+
+def _case_lengths(case, ppb, page):
+    """(slot-space kv lengths, table width) of a named case."""
+    edge, full = ppb * page, 64 * page
+    return {
+        "block_boundary": ([edge], 64),
+        "block_plus_one": ([edge + 1], 64),
+        "one_page": ([page], 64),
+        "full_table": ([full], 64),
+        "ragged": ([edge, edge + 1, page, full, 3 * edge - 5, 1], 64),
+        # a 60-page table is no multiple of 8 or 16 pages a block
+        "table_not_block_multiple": ([60 * page, edge + 1, 7], 60),
+    }[case]
+
+
+def _kernel_and_ref(q, kp, vp, bt, kv_len, poff, kw):
+    out = paged_attention(q, kp, vp, block_tables=bt, kv_len=kv_len,
+                          pos_offset=poff, interpret=True, **kw)
+    exp = ref.paged_attention(q, kp, vp, block_tables=bt, kv_len=kv_len,
+                              pos_offset=poff, **kw)
+    return np.asarray(out), np.asarray(exp)
+
+
+@pytest.mark.parametrize("roll", [0, 128], ids=["no_offset", "pos_offset"])
+@pytest.mark.parametrize("pool", ["bf16", "int8"])
+@pytest.mark.parametrize("case", ["block_boundary", "block_plus_one",
+                                  "one_page", "full_table", "ragged",
+                                  "table_not_block_multiple"])
+def test_pallas_paged_block_stepping(case, pool, roll):
+    """Block-wise walking of live pages equals the gathered oracle at
+    every place a slot can end against the derived block size; the
+    rolled offset shifts absolute lengths and nothing else."""
+    rng = np.random.default_rng(20)
+    ppb = _block_pools(rng, pool, B=1, n_pages=64)[-1]
+    lens, n_pages = _case_lengths(case, ppb, _SB["page"])
+    assert ppb < 64 and 64 % ppb == 0 and 60 % ppb != 0
+    q, kp, vp, bt, kw, _ = _block_pools(rng, pool, B=len(lens),
+                                        n_pages=n_pages)
+    slot_len = jnp.asarray(lens, jnp.int32)
+    poff = jnp.full((len(lens),), roll, jnp.int32)
+    out, exp = _kernel_and_ref(q, kp, vp, bt, slot_len + poff, poff, kw)
+    np.testing.assert_allclose(out, exp, atol=1e-4)
+
+
+@pytest.mark.parametrize("roll", [0, 128], ids=["no_offset", "pos_offset"])
+@pytest.mark.parametrize("pool", ["bf16", "int8"])
+def test_pallas_paged_dead_pages_poisoned(pool, roll):
+    """Every pool page wholly past each slot's live length — the trash
+    page too, which pads the shorter slots' tables — is NaN, values and
+    scales alike. Dead pages never enter the math: the output is finite
+    and bit-identical to the kernel's over the clean pool, and equals
+    the oracle's."""
+    rng = np.random.default_rng(21)
+    q, kp, vp, bt, kw, ppb = _block_pools(rng, pool, B=4, n_pages=64)
+    page = _SB["page"]
+    lens = [ppb * page, ppb * page + 1, page, 3 * ppb * page - 5]
+    live = [-(-n // page) for n in lens]
+    bt = np.asarray(bt).copy()
+    bt[2, live[2]:] = 0                  # a short slot's tail is trash
+    dead = sorted({0} | {int(p) for b, n in enumerate(live)
+                         for p in bt[b, n:]})
+    dead = jnp.asarray(dead)
+    bt = jnp.asarray(bt)
+    poff = jnp.full((4,), roll, jnp.int32)
+    kv_len = jnp.asarray(lens, jnp.int32) + poff
+    clean, exp = _kernel_and_ref(q, kp, vp, bt, kv_len, poff, kw)
+    nan = float("nan")
+    if pool == "int8":
+        # int8 has no NaN: the scales carry it into every dead position
+        kw = dict(k_scales=kw["k_scales"].at[dead].set(nan),
+                  v_scales=kw["v_scales"].at[dead].set(nan))
+        kp, vp = kp.at[dead].set(127), vp.at[dead].set(-127)
+    else:
+        kp, vp = kp.at[dead].set(nan), vp.at[dead].set(nan)
+    out = np.asarray(paged_attention(q, kp, vp, block_tables=bt,
+                                     kv_len=kv_len, pos_offset=poff,
+                                     interpret=True, **kw))
+    assert np.isfinite(out).all()
+    np.testing.assert_array_equal(out, clean)
+    np.testing.assert_allclose(out, exp, atol=1e-4)
+
+
+def test_pallas_paged_true_mqa_soft_cap_fp8():
+    """One kv head for all query heads (Hkv = 1, G = Hq), soft-capped,
+    over an fp8 pool."""
+    rng = np.random.default_rng(22)
+    q, kp, vp, bt = _setup(rng, B=2, Hq=8, Hkv=1, D=128, page=16,
+                           n_pages=8, P=17)
+    kq, ks, vq, vs = _quantize_pools(kp, vp, jnp.float8_e4m3fn)
+    kv_len = jnp.asarray([37, 8 * 16], jnp.int32)
+    out = paged_attention(q, kq, vq, block_tables=bt, kv_len=kv_len,
+                          k_scales=ks, v_scales=vs, logit_soft_cap=30.0,
+                          interpret=True)
+    exp = ref.paged_attention(q, kq, vq, block_tables=bt, kv_len=kv_len,
+                              k_scales=ks, v_scales=vs, logit_soft_cap=30.0)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(exp), atol=1e-4)

@@ -258,6 +258,31 @@ def test_bytes_copied_per_admission_is_zero_paged(engine):
     assert cb.bytes_copied_per_admission() == 0.0
 
 
+def test_kv_pages_walked_counts_live_pages_per_tick(engine):
+    """Each plain decode tick adds the pages the kernel walks: for every
+    active slot, ceil(live length / page), where the live length is the
+    prompt plus the tokens emitted so far (the tick writes and attends
+    the newest one). Two slots of different lengths cross a page
+    boundary while the counter is watched."""
+    cb = ContinuousBatcher(engine, slots=2, max_seq=96, prefix_pages=64)
+    for rid, prompt in (("a", "x" * 14), ("b", PROMPT)):
+        cb.submit(Request(rid=rid, prompt_ids=engine.tokenizer.encode(prompt),
+                          max_new_tokens=12))
+    while cb.queue or cb._adm is not None:
+        cb.step()
+    ticks = 0
+    while all(r is not None for r in cb.active):
+        want = sum(-(-(len(r.prompt_ids) + len(r.output_ids)) // cb.page)
+                   for r in cb.active)
+        before, t = cb.kv_pages_walked, cb.transfers
+        cb.step()
+        assert cb.transfers == t + 1
+        assert cb.kv_pages_walked - before == want
+        ticks += 1
+    assert ticks >= 4
+    assert 0 < cb.kv_pages_walked < cb.transfers * cb.B * cb.n_pages
+
+
 def test_bytes_copied_per_admission_positive_contiguous():
     cfg = get_smoke_config("minitron-8b").replace(vocab_size=300, vocab_pad_to=64)
     e = ServingEngine(cfg, max_seq=96, paged_kv=False)
