@@ -23,7 +23,6 @@ served tokens' place, through the same checks and limits.
 
 from __future__ import annotations
 
-import importlib
 import random
 
 import jax.numpy as jnp
@@ -95,14 +94,14 @@ def _batch(samples, t_pad, rows):
     return ids, tgt, valid
 
 
-def gaps(ref_name: str, params, m: dict, samples, t_pad: int, batch: int,
+def gaps(ref, params, m: dict, samples, t_pad: int, batch: int,
          controls=()) -> dict:
-    """Per served position, how far below the reference's best logit the
-    served token lies: {"served": array}. For each precision in
-    ``controls`` ("fp8") the reference is also run in that
-    precision, and its entry holds the gap, under the reference's
-    logits, of the token that the lower precision puts first."""
-    ref = importlib.import_module(f"reference.{ref_name}")
+    """Per served position, how far below the best logit of the plain
+    reference module ``ref`` the served token lies: {"served": array}.
+    For each precision in ``controls`` ("fp8") the reference is also run
+    in that precision, and its entry holds the gap, under the
+    reference's logits, of the token that the lower precision puts
+    first."""
     w = ref.head_weights(params, m)
     V = m["vocab_size"]
     out = {k: [] for k in ("served", *controls)}

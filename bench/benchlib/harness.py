@@ -25,7 +25,7 @@ import jax
 from benchlib import check, system as sysmod, trace as tracemod
 from benchlib.load import Load
 from benchlib.peaks import peaks
-from benchlib.registry import ROOT, Cell, metric_reader
+from benchlib.registry import ROOT, Cell, metric_reader, reference_module
 from benchlib.stats import percentile
 from benchlib.traffic import generate
 
@@ -67,7 +67,17 @@ def enable_compile_cache():
 
 @dataclass
 class Context:
-    """What a per-layer metric reader may read."""
+    """What a per-layer metric reader may read.
+
+    ``counters0``/``counters1`` are ``system.batcher_counters`` (with the
+    configuration's named ``counters``) at the window's open and close,
+    ``trace_counters0``/``trace_counters1`` the same at the traced
+    window's start and end (``trace_t0``, ``trace_t1``). In a traced run
+    ``extra`` holds ``program_spans``, the program's own ``stream.*``
+    host spans ([name, start_ns, dur_ns], on the trace's clock), and
+    ``sessions``, every ``SessionHandle`` the served engine returned
+    (stamps ``submitted_at``, ``admitted_at``, ``first_token_at`` on the
+    host's ``perf_counter``, the clock of ``t0``)."""
     model: dict
     records: list
     t0: float                     # window open (host perf_counter)
@@ -82,6 +92,9 @@ class Context:
     trace_t1: float = 0.0
     page: int = 16
     extra: dict = field(default_factory=dict)
+    reference: object = None      # the configuration's reference module
+    trace_counters0: dict = field(default_factory=dict)
+    trace_counters1: dict = field(default_factory=dict)
 
     def tokens_between(self, a: float, b: float):
         """(record, index) of every token event the client read in [a, b)."""
@@ -132,6 +145,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     a summary of the traced window's profile to that path for reading
     by hand."""
     cfg, traffic = cell.config, cell.traffic
+    ref = reference_module(cfg["reference"], cell.root)
+    counters = lambda: sysmod.batcher_counters(engine, cfg.get("counters"))
     compiles = CompileCounter()
     tier = traffic["tier"]
     if compile_cache:
@@ -142,14 +157,14 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
 
     stats0 = devs[0].memory_stats() or {}
     if "bytes_limit" in stats0:
-        plan = sysmod.plan_bytes(cfg)
+        plan = sysmod.plan_bytes(cfg, ref)
         limit = cfg["memory_headroom"] * stats0["bytes_limit"]
         _log(f"sizing: planned {plan} B of {stats0['bytes_limit']} B "
              f"(headroom {cfg['memory_headroom']})")
         if plan > limit:
             raise RuntimeError(f"planned {plan} B exceeds {limit:.0f} B")
 
-    system, engine, params = sysmod.build(cfg, seed, tier)
+    system, engine, params = sysmod.build(cfg, ref, seed, tier)
     recorder = sysmod.SubmitRecorder(engine)
     users = int(traffic.get("clients") or traffic.get("users"))
     bearers = [system.globus.issue_token(f"user{i}@uic.edu")
@@ -162,6 +177,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
         raise RuntimeError(f"{len(warm_failed)} warm-up requests failed: "
                            f"{warm_failed[:3]}")
     sysmod.wait_idle(engine)
+    counters()                    # a named counter that is not there fails here
     reqs = generate(traffic, seed)
     if trace:
         sysmod.annotate_host()
@@ -183,18 +199,18 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     def watch():
         while time.perf_counter() < t0:
             time.sleep(0.001)
-        snap["c0"], snap["n0"] = sysmod.batcher_counters(engine), compiles.n
+        snap["c0"], snap["n0"] = counters(), compiles.n
         if trace:
             time.sleep(max(t0 + seconds - trace_s - time.perf_counter(), 0))
             opts = jax.profiler.ProfileOptions()
             opts.python_tracer_level = 0
             opts.host_tracer_level = 2
             jax.profiler.start_trace(trace_dir, profiler_options=opts)
-            snap["tt0"] = time.perf_counter()
+            snap["tt0"], snap["tc0"] = time.perf_counter(), counters()
         time.sleep(max(t0 + seconds - time.perf_counter(), 0))
-        snap["c1"], snap["n1"] = sysmod.batcher_counters(engine), compiles.n
+        snap["c1"], snap["n1"] = counters(), compiles.n
         if trace:
-            snap["tt1"] = time.perf_counter()
+            snap["tt1"], snap["tc1"] = time.perf_counter(), counters()
             jax.profiler.stop_trace()
 
     watcher = threading.Thread(target=watch, daemon=True)
@@ -224,7 +240,11 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
         for m in cell.end_to_end:
             metrics[m["name"]] = {"value": e2e[m["name"]], "unit": m["unit"]}
     else:
-        tr = tracemod.load(trace_dir)
+        # one read of the trace: the benchmark's own bench.* spans stay
+        # the breakdown's, the program's stream.* spans go to the readers
+        tr = tracemod.load(trace_dir, host_prefixes=("bench.", "stream."))
+        program_spans = [e for e in tr["host"] if e[0].startswith("stream.")]
+        tr["host"] = [e for e in tr["host"] if e[0].startswith("bench.")]
         if dump_trace:
             with open(dump_trace, "w") as f:
                 json.dump({"summary": tracemod.summarize(trace_dir),
@@ -239,15 +259,22 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
                       seconds=seconds, wall0=wall0, counters0=snap["c0"],
                       counters1=snap["c1"],
                       task_records=system.endpoint.task_records(), peaks=pk,
-                      trace=tr, trace_t0=snap["tt0"], trace_t1=snap["tt1"])
+                      trace=tr, trace_t0=snap["tt0"], trace_t1=snap["tt1"],
+                      reference=ref, trace_counters0=snap["tc0"],
+                      trace_counters1=snap["tc1"])
         ctx.extra["device_busy_s"] = device["busy_s"]
         ctx.extra["window_s"] = window_s
+        ctx.extra["program_spans"] = program_spans
+        ctx.extra["sessions"] = list(recorder.handles.values())
         for m in cell.per_layer:
             v = metric_reader(m["name"], cell.root)(ctx)
             if v is not None:
                 metrics[m["name"]] = {"value": v, "unit": m["unit"]}
         _log("trace lines: " + ", ".join(
             f"{k} ({len(v)})" for k, v in tr["device"].items()))
+        _log(f"traced window: {window_s:.6f} s, decode ticks "
+             f"{snap['tc1']['transfers'] - snap['tc0']['transfers']}, "
+             f"program spans {len(program_spans)}")
 
     # ---------------------------------------------------------- check
     mismatch = check.stream_mismatches(records, recorder.handles)
@@ -259,7 +286,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     gc.collect()
     t_ref = time.perf_counter()
     t_pad = (traffic["prompt_tokens"]["max"] + traffic["output_tokens"]["max"])
-    g = check.gaps(cfg["reference"], params, cfg["model"], samples, t_pad,
+    g = check.gaps(ref, params, cfg["model"], samples, t_pad,
                    chk["batch"], controls=(control,) if control else ())
     _log(f"reference: {len(samples)} requests, {len(g['served'])} served "
          f"tokens, {time.perf_counter() - t_ref:.3f} s")

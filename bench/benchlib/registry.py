@@ -5,6 +5,9 @@ Everything that belongs to one configuration, one traffic mix or one
 per-layer metric lives in a file of its own:
 
   bench/configs/<config>.json        sizes, build arguments, reference, limits
+  bench/reference/<reference>.py     the plain reference the configuration
+                                     names: its forward pass, the weight
+                                     layout it reads and its op counts
   bench/traffic/<traffic>.json       parameters for the one traffic generator
   bench/layer_metrics/<metric>.py    ``read(ctx) -> float | None``; a metric
                                      split by cell, ``<metric>.<cell>``, may
@@ -16,9 +19,11 @@ editing one that exists.
 
 from __future__ import annotations
 
+import hashlib
 import importlib.util
 import json
 import os
+import sys
 from dataclasses import dataclass
 
 BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -86,3 +91,19 @@ def metric_reader(name: str, root: str = ROOT):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod.read
+
+
+def reference_module(name: str, root: str = ROOT):
+    """The plain reference module ``bench/reference/<name>.py`` of the
+    checkout at ``root``: ``weight_spec(m)``, ``decode_flops(m, kv_len)``,
+    ``prefill_flops(m, start, n)``, and for the check ``final_hidden``
+    and ``head_weights``. Loaded once per file and process, so its jitted
+    functions compile once."""
+    path = os.path.abspath(os.path.join(root, "bench", "reference", f"{name}.py"))
+    key = "bench_reference_" + hashlib.sha1(path.encode()).hexdigest()[:12]
+    if key not in sys.modules:
+        spec = importlib.util.spec_from_file_location(key, path)
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        sys.modules[key] = mod
+    return sys.modules[key]

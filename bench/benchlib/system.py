@@ -10,6 +10,7 @@ before any request reaches the engine.
 from __future__ import annotations
 
 import gc
+import operator
 import threading
 import time
 
@@ -51,9 +52,10 @@ class SubmitRecorder:
         return h
 
 
-def plan_bytes(config: dict) -> int:
+def plan_bytes(config: dict, ref) -> int:
     """Planned device bytes of the served tier (chip_smoke's rule):
-    weights, plus the KV page pool twice over for attention models (the
+    weights (the layout of the configuration's reference module ``ref``),
+    plus the KV page pool twice over for attention models (the
     fused tick does not donate it, so two copies are live), or the state
     snapshot pool plus two copies of the slot states for recurrent ones."""
     from repro.models import build_model
@@ -63,7 +65,8 @@ def plan_bytes(config: dict) -> int:
     model = build_model(ModelConfig(**m))
     nbytes = lambda tree: int(sum(np.prod(x.shape) * x.dtype.itemsize
                                   for x in jax.tree.leaves(tree) if x.ndim))
-    params = nbytes(jax.eval_shape(lambda: weights.make(m, 0)))
+    params = nbytes(jax.eval_shape(
+        lambda: weights.make(ref.weight_spec(m), m["param_dtype"], 0)))
     page, pages, slots = 16, b["prefix_cache_pages"], b["scheduler_slots"]
     if has_state_leaves(cache_layout(model.cache_specs())):
         state = nbytes(jax.eval_shape(lambda: model.init_cache(1, 1)))
@@ -72,10 +75,11 @@ def plan_bytes(config: dict) -> int:
     return params + 2 * (pages + 1) * slot_page
 
 
-def build(config: dict, seed: int, tier: str):
+def build(config: dict, ref, seed: int, tier: str):
     """The whole system, the engine of ``tier`` (the traffic's) built at
     the configuration's sizes and holding the benchmark's weights for
-    ``seed``; the other tiers stay at the program's smoke size. Returns
+    ``seed``, in the layout of the configuration's reference module
+    ``ref``; the other tiers stay at the program's smoke size. Returns
     (system, engine, params)."""
     from repro.core import build_system
 
@@ -86,7 +90,8 @@ def build(config: dict, seed: int, tier: str):
     shapes = jax.eval_shape(engine.model.init, jax.random.PRNGKey(0))
     engine.params = None          # the program's own draw is not served
     gc.collect()
-    params = weights.make(config["model"], seed)
+    m = config["model"]
+    params = weights.make(ref.weight_spec(m), m["param_dtype"], seed)
     bad = weights.same_layout(params, shapes)
     if bad:
         raise RuntimeError(f"benchmark weights do not fit the model: {bad[:5]}")
@@ -138,11 +143,17 @@ def warm(system, bearer: str, config: dict, traffic: dict, seed: int,
     return [r.error or f"status {r.status}" for r in recs if not r.ok]
 
 
-def batcher_counters(engine) -> dict:
+def batcher_counters(engine, named: dict | None = None) -> dict:
+    """The served engine's batcher counters (decode ticks ``transfers``,
+    ``admissions``, ``slots``), plus each of ``named`` ({name: dotted
+    attribute path on the engine}, a configuration's ``counters``), e.g.
+    ``{"pages_walked": "scheduler.batcher.kv_pages_walked"}``."""
     b = engine.scheduler.batcher if engine.scheduler is not None else None
-    if b is None:
-        return {"transfers": 0, "admissions": 0, "slots": 0}
-    return {"transfers": b.transfers, "admissions": b.admissions, "slots": b.B}
+    out = ({"transfers": 0, "admissions": 0, "slots": 0} if b is None else
+           {"transfers": b.transfers, "admissions": b.admissions, "slots": b.B})
+    for name, path in (named or {}).items():
+        out[name] = operator.attrgetter(path)(engine)
+    return out
 
 
 def annotate_host():

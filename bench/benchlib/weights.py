@@ -1,55 +1,25 @@
 """Random weights drawn by the benchmark from the seed, on the device, in
 one jitted call, in the dtype they are served in.
 
-The tree follows the served model's parameter layout (the names the
-program's model reads), and the reference reads the same names: the
+The tree follows the layout that the configuration's reference module
+declares (``weight_spec(m)`` in ``bench/reference/<reference>.py``: the
+names the program's model reads, and the reference reads the same): the
 program is handed these arrays and the reference uses the benchmark's
-own copy, so the reference takes nothing the program made.
+own copy, so the reference takes nothing the program made. A new model
+family brings its layout in its reference module; nothing here changes.
 
-Scales: matrices are N(0, 1/fan_in) (output projections also divided by
-sqrt(2 * n_layers)), embeddings N(0, 1), norm gains and gate biases
+Kinds: "normal" draws N(0, scale^2), "gain" 1 + N(0, scale^2). The
+layouts give matrices N(0, 1/fan_in) (output projections also divided
+by sqrt(2 * n_layers)), embeddings N(0, 1), norm gains and gate biases
 1 + N(0, 0.1), so that every leaf is exercised.
 """
 
 from __future__ import annotations
 
-import math
 from functools import partial
 
 import jax
 import jax.numpy as jnp
-
-
-def _dense_spec(m: dict) -> dict:
-    L, d, f = m["n_layers"], m["d_model"], m["d_ff"]
-    q, kv = m["n_heads"] * m["head_dim"], m["n_kv_heads"] * m["head_dim"]
-    vp = -(-m["vocab_size"] // m["vocab_pad_to"]) * m["vocab_pad_to"]
-    out = 1.0 / math.sqrt(2 * L)
-    spec = {
-        "embed": {"w": ((vp, d), "normal", 1.0)},
-        "blocks": {
-            "ln1": {"w": ((L, d), "gain", 0.1)},
-            "ln2": {"w": ((L, d), "gain", 0.1)},
-            "attn": {"wq": ((L, d, q), "normal", d ** -0.5),
-                     "wk": ((L, d, kv), "normal", d ** -0.5),
-                     "wv": ((L, d, kv), "normal", d ** -0.5),
-                     "wo": ((L, q, d), "normal", q ** -0.5 * out)},
-            "mlp": {"w1": ((L, d, f), "normal", d ** -0.5),
-                    "w3": ((L, d, f), "normal", d ** -0.5),
-                    "w2": ((L, f, d), "normal", f ** -0.5 * out)},
-        },
-        "ln_f": {"w": ((d,), "gain", 0.1)},
-    }
-    if not m.get("tie_embeddings"):
-        spec["lm_head"] = {"w": ((vp, d), "normal", d ** -0.5)}
-    return spec
-
-
-def spec(m: dict) -> dict:
-    """{path: (shape, kind, scale)} nested like the parameter tree."""
-    if m["family"] == "dense":
-        return _dense_spec(m)
-    raise ValueError(f"no weight layout for family {m['family']!r}")
 
 
 def _is_leaf(x):
@@ -66,13 +36,14 @@ def _draw(leaves: tuple, key, dtype):
     return out
 
 
-def make(m: dict, seed: int):
-    """The weight tree of model dict ``m`` for ``seed``, in its
-    ``param_dtype``, drawn in one jitted call on the default device."""
-    leaves, treedef = jax.tree.flatten(spec(m), is_leaf=_is_leaf)
+def make(layout: dict, dtype, seed: int):
+    """The weight tree of ``layout`` ({path: (shape, kind, scale)}, nested
+    like the parameter tree) for ``seed``, in ``dtype``, drawn in one
+    jitted call on the default device. Leaf i of the flattened layout
+    draws from ``fold_in(key, i)``."""
+    leaves, treedef = jax.tree.flatten(layout, is_leaf=_is_leaf)
     key = jax.random.PRNGKey(seed % (2 ** 31 - 1))
-    return treedef.unflatten(_draw(tuple(leaves), key,
-                                   jnp.dtype(m["param_dtype"])))
+    return treedef.unflatten(_draw(tuple(leaves), key, jnp.dtype(dtype)))
 
 
 def same_layout(params, shapes) -> list[str]:

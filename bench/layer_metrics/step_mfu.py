@@ -1,5 +1,6 @@
 """Model step, whole: model operations of the decode ticks and prompt
-prefills in the traced window (``benchlib.costs``) over the device time
+prefills in the traced window (counted by the configuration's reference
+module: ``benchlib.layers.model_flops``) over the device time
 of the fused-tick and prefill-chunk programs, as a share of the chip's
 bf16 peak."""
 

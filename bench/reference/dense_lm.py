@@ -8,16 +8,55 @@ Whole-sequence causal attention in float32 at the highest matmul
 precision, block by block (one block's weights widened at a time, so
 it runs beside the served weights on one chip). Imports nothing of the
 program.
+
+Besides the forward pass, the module states what the benchmark needs of
+this family: ``weight_spec``, the weight layout it reads (drawn by
+``benchlib.weights``), and ``decode_flops`` / ``prefill_flops``, the
+operations a token costs (read by ``step_mfu``).
 """
 
 from __future__ import annotations
 
+import math
 from functools import partial
 
 import jax
 import jax.numpy as jnp
 
+from benchlib import costs
 from reference.common import F32, HIGHEST, linear, rmsnorm
+
+
+def weight_spec(m: dict) -> dict:
+    """{path: (shape, kind, scale)} nested like the parameter tree; the
+    kinds and scales are ``benchlib.weights``'s."""
+    L, d, f = m["n_layers"], m["d_model"], m["d_ff"]
+    q, kv = m["n_heads"] * m["head_dim"], m["n_kv_heads"] * m["head_dim"]
+    vp = -(-m["vocab_size"] // m["vocab_pad_to"]) * m["vocab_pad_to"]
+    out = 1.0 / math.sqrt(2 * L)
+    spec = {
+        "embed": {"w": ((vp, d), "normal", 1.0)},
+        "blocks": {
+            "ln1": {"w": ((L, d), "gain", 0.1)},
+            "ln2": {"w": ((L, d), "gain", 0.1)},
+            "attn": {"wq": ((L, d, q), "normal", d ** -0.5),
+                     "wk": ((L, d, kv), "normal", d ** -0.5),
+                     "wv": ((L, d, kv), "normal", d ** -0.5),
+                     "wo": ((L, q, d), "normal", q ** -0.5 * out)},
+            "mlp": {"w1": ((L, d, f), "normal", d ** -0.5),
+                    "w3": ((L, d, f), "normal", d ** -0.5),
+                    "w2": ((L, f, d), "normal", f ** -0.5 * out)},
+        },
+        "ln_f": {"w": ((d,), "gain", 0.1)},
+    }
+    if not m.get("tie_embeddings"):
+        spec["lm_head"] = {"w": ((vp, d), "normal", d ** -0.5)}
+    return spec
+
+
+# Operations of the dense GQA decoder: ``benchlib.costs``'s arithmetic.
+decode_flops = costs.decode_flops
+prefill_flops = costs.prefill_flops
 
 
 def _rope(x, theta):

@@ -24,11 +24,12 @@ CONTROL = "fp8"          # the precision below bf16 the limits are set to catch
 
 
 def tiny_root(tmp_path) -> str:
-    """A checkout whose BENCHMARK.json names the test-size cells."""
+    """A checkout whose BENCHMARK.json names the test-size cells, with the
+    benchmark's own readers and reference modules."""
     root = os.path.join(str(tmp_path), "checkout")
     shutil.copytree(FIXTURES, root)
-    shutil.copytree(os.path.join(BENCH, "layer_metrics"),
-                    os.path.join(root, "bench", "layer_metrics"))
+    for d in ("layer_metrics", "reference"):
+        shutil.copytree(os.path.join(BENCH, d), os.path.join(root, "bench", d))
     return root
 
 

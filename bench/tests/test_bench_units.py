@@ -187,6 +187,133 @@ def test_paged_attention_cost_matches_hand_count():
     assert costs.roofline_seconds(flops, nbytes, pk) == pytest.approx(max(flops / 100, nbytes / 10))
 
 
+# ------------------------------------------------------------------ the mistral cell, pinned
+# The dense layout and op counts moved from ``benchlib`` into the
+# reference module the configuration names; these pin them to what the
+# harness drew and counted before the move.
+PINNED_SEED = 2 ** 31 + 77
+PINNED_SHA256 = "361cee0ab5fb814a6fdd92e074af9755ca9e16d94c5b197939e7124c2beccb09"
+
+
+def _config(path):
+    with open(path) as f:
+        return json.load(f)
+
+
+def test_test_size_mistral_weights_are_bit_identical_to_the_pinned_draw():
+    import hashlib
+    import jax
+    from benchlib import weights
+    m = _config(os.path.join(FIXTURES, "bench", "configs", "tiny-dense.json"))["model"]
+    ref = registry.reference_module("dense_lm", ROOT)
+    p = weights.make(ref.weight_spec(m), m["param_dtype"], PINNED_SEED)
+    h = hashlib.sha256()
+    for path, leaf in jax.tree_util.tree_flatten_with_path(p)[0]:
+        a = np.asarray(leaf)
+        h.update(jax.tree_util.keystr(path).encode())
+        h.update(str(a.shape).encode())
+        h.update(str(a.dtype).encode())
+        h.update(a.tobytes())
+    assert h.hexdigest() == PINNED_SHA256
+
+
+def test_mistral_model_flops_are_the_dense_arithmetic():
+    from benchlib import layers
+    cell = registry.find_cell("mistral7b-hpc-api", ROOT)
+    m = cell.config["model"]
+    ref = registry.reference_module(cell.config["reference"], ROOT)
+    recs = [_record(i, 0.1 * i, 0.05, 3 + i, 0.02) for i in range(5)]
+    for i, r in enumerate(recs):
+        r.req = Request(index=i, principal=0, prompt="x" * (16 + 40 * i),
+                        prompt_tokens=17 + 40 * i, max_tokens=3 + i)
+    ctx = harness.Context(model=m, records=recs, t0=0.0, seconds=10.0, wall0=0.0,
+                          counters0={}, counters1={}, task_records=[], peaks={},
+                          reference=ref)
+    want = (sum(costs.decode_flops(m, r.req.prompt_tokens + i)
+                for r in recs for i in range(1, len(r.token_times)))
+            + sum(costs.prefill_flops(m, 0, r.req.prompt_tokens) for r in recs))
+    assert layers.model_flops(ctx, 0.0, 10.0) == want
+    # a window that cuts a request counts only the tokens read inside it
+    a, b = recs[2].token_times[1], recs[2].token_times[-1]
+    part = layers.model_flops(ctx, a, b)
+    assert 0 < part < want
+
+
+# ------------------------------------------------------------------ counters
+class _Batcher:
+    transfers, admissions, B, kv_pages_walked = 41, 7, 32, 1234
+
+
+class _Engine:
+    def __init__(self, scheduler):
+        self.scheduler = scheduler
+
+
+def test_batcher_counters_add_the_configuration_s_named_ones():
+    from types import SimpleNamespace
+    from benchlib import system as sysmod
+    eng = _Engine(SimpleNamespace(batcher=_Batcher()))
+    assert sysmod.batcher_counters(eng) == {"transfers": 41, "admissions": 7, "slots": 32}
+    named = {"pages_walked": "scheduler.batcher.kv_pages_walked"}
+    assert sysmod.batcher_counters(eng, named) == {
+        "transfers": 41, "admissions": 7, "slots": 32, "pages_walked": 1234}
+    with pytest.raises(AttributeError):
+        sysmod.batcher_counters(eng, {"x": "scheduler.batcher.no_such_counter"})
+    assert sysmod.batcher_counters(_Engine(None)) == {"transfers": 0, "admissions": 0,
+                                                      "slots": 0}
+
+
+# ------------------------------------------------------------------ program spans
+def _span_ctx():
+    """A traced window: 4 decode ticks between the trace's counters; the
+    program's spans and sessions (ns on the trace's clock, s on the
+    host's); device ops at [0, 10), [30, 40), [100, 110) ns."""
+    from types import SimpleNamespace as S
+    ms, us = 1_000_000, 1_000
+    spans = ([["stream.batcher.admit", 0, 2 * ms], ["stream.batcher.admit", 0, 6 * ms]]
+             + [["stream.batcher.decode", 0, 30 * ms]] * 4
+             + [["stream.batcher.emit", 15, 10], ["stream.batcher.emit", 50, 100],
+                ["stream.batcher.emit", 1000, 40 * ms - 110]]
+             + [["stream.batcher.tick", 0, 200 * ms]]
+             + [["stream.relay.encrypt", 0, d * us] for d in (1000, 1200, 1400)]
+             + [["stream.relay.decrypt", 0, d * us] for d in (400, 500)])
+    sessions = [S(submitted_at=10.0, admitted_at=10.1, first_token_at=10.5),
+                S(submitted_at=11.0, admitted_at=11.3, first_token_at=11.4),
+                S(submitted_at=12.0, admitted_at=12.2, first_token_at=13.0),
+                S(submitted_at=9.0, admitted_at=19.0, first_token_at=29.0),   # lead-in
+                S(submitted_at=20.0, admitted_at=20.0, first_token_at=20.0)]  # after close
+    tr = {"device": {tracemod.OPS_LINE: [["a", 0, 10], ["b", 30, 10], ["c", 100, 10]]},
+          "host": []}
+    ctx = harness.Context(model=dict(SMALL), records=[], t0=10.0, seconds=10.0,
+                          wall0=0.0, counters0={"transfers": 0}, counters1={"transfers": 99},
+                          task_records=[], peaks={}, trace=tr,
+                          trace_counters0={"transfers": 10, "slots": 4},
+                          trace_counters1={"transfers": 14, "slots": 4})
+    ctx.extra.update(program_spans=spans, sessions=sessions)
+    return ctx
+
+
+@pytest.mark.parametrize("name,want", [
+    ("queue_wait_ms.api", 200.0),     # waits 100, 300, 200 ms in the window
+    ("admit_ms.api", 400.0),          # 400, 100, 800 ms
+    ("tick_admit_ms.api", 2.0),       # 8 ms of admit spans over 4 traced ticks
+    ("tick_decode_ms.api", 30.0),
+    ("tick_emit_ms.api", 10.0),       # 40 ms of emit spans over 4 ticks
+    ("relay_encrypt_us", 1200.0),
+    ("relay_decrypt_us", 450.0),
+    ("idle_emit_share.api", 75.0),    # gaps of 20 + 60 ns; emit covers 10 + 50
+])
+def test_program_span_readers_on_a_synthetic_window(name, want):
+    ctx = _span_ctx()
+    assert registry.metric_reader(name, ROOT)(ctx) == pytest.approx(want)
+    # with no traced counters or no spans there is nothing to read
+    bare = _span_ctx()
+    bare.trace_counters0 = bare.trace_counters1 = {}
+    bare.extra.update(program_spans=[], sessions=[])
+    bare.trace = None
+    assert registry.metric_reader(name, ROOT)(bare) is None
+
+
 # ------------------------------------------------------------------ trace
 def _fixture_trace():
     with open(os.path.join(FIXTURES, "trace_small.json")) as f:

@@ -23,7 +23,7 @@ import time
 BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path[:0] = [BENCH, os.path.join(os.path.dirname(BENCH), "src")]
 
-from benchlib.registry import find_cell  # noqa: E402
+from benchlib.registry import find_cell, reference_module  # noqa: E402
 
 
 def backlog(records, t0: float, seconds: float) -> dict:
@@ -63,7 +63,8 @@ def main(argv=None) -> int:
 
     harness.enable_compile_cache()
     cfg, traffic = cell.config, cell.traffic
-    system, engine, _ = sysmod.build(cfg, args.seed, traffic["tier"])
+    ref = reference_module(cfg["reference"], cell.root)
+    system, engine, _ = sysmod.build(cfg, ref, args.seed, traffic["tier"])
     try:
         bearers = [system.globus.issue_token(f"user{i}@uic.edu")
                    for i in range(int(traffic["users"]))]
